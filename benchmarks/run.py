@@ -47,6 +47,9 @@ def _all_benchmarks():
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     argv = argv if argv is not None else sys.argv[1:]
     benches = _all_benchmarks()
     names = argv or list(benches)

@@ -1,44 +1,33 @@
-"""Version compatibility shims for the jax API surface this repo targets.
+"""The jax API spellings this repo uses, in one place.
 
-The codebase is written against the modern spellings (``jax.shard_map``
-with ``check_vma``, ``jax.make_mesh(..., axis_types=...)``); older
-jaxlibs (< 0.5) expose the same functionality as
-``jax.experimental.shard_map.shard_map(..., check_rep=...)`` and a
-``make_mesh`` without ``axis_types``. Route every use through here so the
-rest of the tree stays on one spelling.
+``shard_map`` without the replication check and meshes with explicit
+Auto axis types: every caller goes through here so the tree stays on
+one spelling.
 """
 from __future__ import annotations
 
 import jax
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` with the replication-check kwarg spelled per
-    the installed jax version (``check_vma`` >= 0.5, ``check_rep`` before)."""
-    return _shard_map(
+    """``jax.shard_map`` (``check_vma`` off by default)."""
+    return jax.shard_map(
         f,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
+        check_vma=check_vma,
     )
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, devices=None):
+    """A mesh with explicit Auto axis types. Over every visible device
+    (``devices=None``) this is ``jax.make_mesh``, which orders devices by
+    the physical topology; over an explicit device list the devices are
+    laid out in the order given."""
+    types = (AxisType.Auto,) * len(axes)
+    if devices is None:
+        return jax.make_mesh(shape, axes, axis_types=types)
+    return Mesh(np.asarray(devices).reshape(shape), axes, axis_types=types)

@@ -68,6 +68,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.compat import shard_map
@@ -1901,10 +1902,17 @@ def _moe_apply(x2d, mp, sig: LayerSig, ctx: Ctx, gathered: dict, rows: int,
 
     if xp.mode == "replicated" or pl.group_size == 1:
         xe = moe_lib.dispatch_tokens(x2d, d, e_pad, cap)
-        ye = moe_lib.grouped_ffn(
-            xe, mp["experts"]["w_gate"], mp["experts"]["w_up"],
-            mp["experts"]["w_down"],
-        )
+        ex = mp["experts"]
+        banks = (ex["w_gate"], ex["w_up"], ex["w_down"])
+        if split_gemm_lib.default_dense_impl(xp.phase) == "pallas":
+            # every expert is resident: the §4.2 kernel over the local
+            # bank with an empty remote one (on a TPU; the jnp grouped
+            # FFN below is the same math, cheaper in interpret mode)
+            ye = split_gemm_lib.split_swiglu(
+                xe, *banks, *(w[:0] for w in banks), impl="pallas"
+            )
+        else:
+            ye = moe_lib.grouped_ffn(xe, *banks)
     elif demand_fetch_active(cfg, geom, xp, ctx.group):
         # route-before-gather: the routing above used only the LOCAL
         # router weights, so the expert gather can now be demand-driven.
@@ -2812,5 +2820,30 @@ def make_step_fn(model: Model, xp: ExecutionPlan, mesh, *, capture_len: int = 0)
         out_specs=out_specs,
         check_vma=False,
     )
-    # donate the KV cache / recurrent state: serving updates it in place
-    return jax.jit(sharded, donate_argnums=(2,))
+    # donate the KV cache / recurrent state: serving updates it in place.
+    # The outputs are pinned to NamedShardings spelled exactly as
+    # ``state_shardings`` spells them, so the state a step returns and
+    # the state the server commits (boot, warmup, admit) key ONE jit
+    # executable whatever form JAX would normalize the specs to.
+    return jax.jit(
+        sharded, donate_argnums=(2,),
+        out_shardings=jax.tree.map(
+            lambda s: NamedSharding(mesh, s), out_specs,
+            is_leaf=lambda s: isinstance(s, P),
+        ),
+    )
+
+
+def state_shardings(model: Model, xp: ExecutionPlan, mesh) -> dict:
+    """The decode step's state shardings (KV/recurrent slots plus the
+    predictive-fetch state, when the plan runs it) — what the server
+    commits its state to and what :func:`make_step_fn` pins the step's
+    state output to. Optional ``PredictState`` fields are ``None``."""
+    specs = dict(state_pspecs(model, xp))
+    pred = predict_state_pspecs(model, xp)
+    if pred:
+        specs["pred"] = pred
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs,
+        is_leaf=lambda s: isinstance(s, P),
+    )

@@ -1,14 +1,8 @@
-"""jit'd public wrapper for the flash attention kernel."""
+"""Public entry points: the flash attention kernel (Mosaic on a TPU,
+interpret mode elsewhere) and its pure-jnp reference."""
 from __future__ import annotations
 
-from repro.kernels import resolve_interpret
-from repro.kernels.flash_attention.flash_attention import flash_attention as _fa
+from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
-
-
-def flash_attention(q, k, v, **kw):
-    kw.setdefault("interpret", resolve_interpret())
-    return _fa(q, k, v, **kw)
-
 
 __all__ = ["flash_attention", "flash_attention_ref"]

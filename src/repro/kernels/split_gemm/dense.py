@@ -31,11 +31,13 @@ no merge copy anywhere.
   split path needs no roll whatsoever.
 
 Block sizes auto-select per dimension exactly like the grouped kernels
-(largest lane-friendly divisor, single-block fallback), so decode-scale
-token counts stream correctly. The fused SwiGLU also shares the grouped
+(largest lane-friendly divisor for weights; the token dim one block up to
+``block_c`` and zero-padded to a multiple of it beyond), so decode-scale
+and odd prompt-length token counts stream correctly. The fused SwiGLU also shares the grouped
 kernel's down-projection output-dim blocking (``block_o``, auto-selected
-against the 8 MiB fp32 VMEM accumulator budget), so d_model beyond the
-single-pass accumulator envelope lowers on the dense path too.
+so that the accumulators and the double-buffered tiles fit the step's
+VMEM budget), so d_model beyond the single-pass envelope lowers on the
+dense path too.
 """
 from __future__ import annotations
 
@@ -48,10 +50,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 from repro.kernels.split_gemm.split_gemm import (
-    _auto_block_o,
+    _auto_blocks,
     _cast,
     _dummy_banks,
+    _pad_rows,
     _pick_block,
+    _row_block,
 )
 
 
@@ -109,11 +113,12 @@ def split_stack_gemm(
     n_wl = w_local.shape[0]
     n_wr = w_remote.shape[0]
 
-    bc = _pick_block(t, block_c)
+    t_pad, bc = _row_block(t, block_c)
+    x = _pad_rows(x, 0, t_pad)
     bf = _pick_block(f, block_f)
     bd = _pick_block(d, block_d)
 
-    grid = (s, t // bc, f // bf, d // bd)
+    grid = (s, t_pad // bc, f // bf, d // bd)
 
     def x_map(si, ci, fi, di):
         return (ci, di)
@@ -136,10 +141,10 @@ def split_stack_gemm(
             pl.BlockSpec((1, bd, bf), wr_map),
         ],
         out_specs=pl.BlockSpec((1, bc, bf), o_map),
-        out_shape=jax.ShapeDtypeStruct((s, t, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, t_pad, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=resolve_interpret(interpret),
-    )(x, w_local, w_remote)
+    )(x, w_local, w_remote)[:, :t]
 
 
 # ==========================================================================
@@ -203,11 +208,12 @@ def split_reduce_gemm(
     n_wl = w_local.shape[0]
     n_wr = w_remote.shape[0]
 
-    bc = _pick_block(t, block_c)
+    t_pad, bc = _row_block(t, block_c)
+    x = _pad_rows(x, 1, t_pad)
     bo = _pick_block(d, block_o)
     bk = _pick_block(f, block_k)
 
-    grid = (t // bc, d // bo, s, f // bk)
+    grid = (t_pad // bc, d // bo, s, f // bk)
 
     def x_map(ci, oi, si, ki):
         return (si, ci, ki)
@@ -230,10 +236,10 @@ def split_reduce_gemm(
             pl.BlockSpec((1, bk, bo), wr_map),
         ],
         out_specs=pl.BlockSpec((bc, bo), o_map),
-        out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bo), jnp.float32)],
         interpret=resolve_interpret(interpret),
-    )(x, w_local, w_remote)
+    )(x, w_local, w_remote)[:t]
 
 
 # ==========================================================================
@@ -329,9 +335,10 @@ def split_dense_swiglu(
     d_model beyond the VMEM accumulator budget still lowers: with
     n_o = D/block_o output blocks the gate/up stages are recomputed once
     per block (the standard recompute-vs-residency trade), and
-    ``block_o=None`` auto-selects — the full D (the previous single-pass
-    (bc, D) schedule) whenever it fits the shared ``_ACC_BUDGET_BYTES``,
-    the largest fitting divisor otherwise."""
+    ``block_o=None`` auto-selects with the grouped kernel's
+    ``_auto_blocks`` — the full D (the single-pass (bc, D) schedule)
+    whenever the step's tiles fit its VMEM budget, the largest fitting
+    divisor otherwise."""
     t, d = x.shape
     s_l = wg_local.shape[0]
     s_r = wg_remote.shape[0]
@@ -343,12 +350,19 @@ def split_dense_swiglu(
     n_wl = wg_local.shape[0]
     n_wr = wg_remote.shape[0]
 
-    bc = _pick_block(t, block_c)
+    t_pad, bc = _row_block(t, block_c)
+    x = _pad_rows(x, 0, t_pad)
     bf = _pick_block(f, block_f)
     bd = _pick_block(d, block_d)
-    bo = _auto_block_o(d, bc, bf) if block_o is None else _pick_block(d, block_o)
+    if block_o is None:
+        bf, bo = _auto_blocks(
+            d, f, bc, bf, bd, x.dtype.itemsize,
+            max(wg_local.dtype.itemsize, wg_remote.dtype.itemsize),
+        )
+    else:
+        bo = _pick_block(d, block_o)
 
-    grid = (t // bc, d // bo, s, f // bf, d // bd)
+    grid = (t_pad // bc, d // bo, s, f // bf, d // bd)
 
     def x_map(ci, oi, si, fi, di):
         return (ci, di)
@@ -381,11 +395,11 @@ def split_dense_swiglu(
             pl.BlockSpec((1, bf, bo), down_r_map),
         ],
         out_specs=pl.BlockSpec((bc, bo), o_map),
-        out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((t_pad, d), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bo), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(x, wg_local, wu_local, wd_local, wg_remote, wu_remote, wd_remote)
+    )(x, wg_local, wu_local, wd_local, wg_remote, wu_remote, wd_remote)[:t]

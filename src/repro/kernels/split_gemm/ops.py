@@ -29,7 +29,10 @@ and dense-FFN projection of every layer, so ``default_dense_impl`` picks
 pallas only on a real TPU and the (equally merge-free, numerically
 matching) jnp formulation elsewhere — keeping the CPU test suite's
 interpret-mode cost bounded while the kernels themselves stay covered by
-the dedicated interpret-mode sweeps in tests/test_kernels.py.
+the dedicated interpret-mode sweeps in tests/test_kernels.py. The
+single-rank MoE layer (every expert resident, nothing gathered) follows
+the same policy: ``split_swiglu`` with an empty remote bank on a TPU,
+the jnp grouped FFN elsewhere.
 """
 from __future__ import annotations
 
@@ -59,7 +62,9 @@ from repro.models.moe import grouped_ffn
 
 
 def default_dense_impl(phase: str) -> str:
-    """Engine policy for the dense/attention split ops (see module doc)."""
+    """Engine policy for the dense/attention split ops (see module doc),
+    shared by the single-rank MoE layer, whose whole expert bank is
+    resident."""
     if phase == "train":
         return "jnp"
     return "pallas" if jax.default_backend() == "tpu" else "jnp"

@@ -21,7 +21,7 @@ Three kernels:
 - ``split_grouped_swiglu_demand``: the on-demand variant. The remote
   operand is the *compacted* demand-fetched bank — ``(budget, D, F)``
   rows of exactly the routing-activated experts, padded to the static
-  budget — plus a per-row validity mask streamed through SMEM. Invalid
+  budget — plus a per-row validity mask held whole in SMEM. Invalid
   (padding) rows hold clamped junk weights; the mask predicates every
   MXU stage for them, so their output blocks flush the zero-initialized
   accumulator and the padding costs no FLOPs.
@@ -33,11 +33,12 @@ so the accumulator carries across it (standard Pallas matmul pipelining).
 The SwiGLU's D/bo coordinate blocks the down-projection *output* dim so
 d_model beyond the VMEM accumulator budget lowers (bo = D, i.e. a single
 output block, whenever it fits — gate/up recompute only kicks in when
-blocking does). Block sizes are auto-selected per dimension (largest
-lane-friendly divisor), so capacities that are not multiples of 128 —
-e.g. decode-scale MoE capacities, which ``capacity_for`` only rounds to
-8 — stream correctly; a dimension with no aligned divisor falls back to
-a single block.
+blocking does). Weight block sizes are auto-selected per dimension
+(largest lane-friendly divisor, a single block where none divides). The
+capacity (token) dim is one block up to ``block_c`` — decode-scale MoE
+capacities, which ``capacity_for`` keeps exact below 8 — and is
+zero-padded to a multiple of ``block_c`` beyond it when that does not
+divide it (``_row_block``).
 
 The dense (non-grouped) siblings — ``split_stack_gemm``,
 ``split_reduce_gemm``, ``split_dense_swiglu`` in ``dense.py`` — extend
@@ -70,6 +71,26 @@ def _pick_block(n: int, preferred: int) -> int:
         if b <= preferred and n % b == 0:
             return b
     return n
+
+
+def _row_block(n: int, preferred: int) -> tuple[int, int]:
+    """(padded rows, block) for a token or capacity dim. Rows past
+    ``preferred`` that it does not divide are zero-padded up to a multiple
+    of it: a single (n, ...) block of a long odd dim overruns VMEM, and a
+    small divisor re-streams every weight tile once per row block."""
+    if n <= preferred or n % preferred == 0:
+        return n, min(n, preferred)
+    return -(-n // preferred) * preferred, preferred
+
+
+def _pad_rows(x: jax.Array, axis: int, n: int) -> jax.Array:
+    """``x`` zero-padded along ``axis`` to ``n`` rows. Zero rows stay
+    zero through every kernel here and are sliced off the output."""
+    if x.shape[axis] == n:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, n - x.shape[axis])
+    return jnp.pad(x, pad)
 
 
 def _cast(w, like):
@@ -139,11 +160,12 @@ def split_grouped_gemm(
     n_wl = w_local.shape[0]
     n_wr = w_remote.shape[0]
 
-    bc = _pick_block(c, block_c)
+    c_pad, bc = _row_block(c, block_c)
+    x = _pad_rows(x, 1, c_pad)
     bf = _pick_block(f, block_f)
     bd = _pick_block(d, block_d)
 
-    grid = (e, c // bc, f // bf, d // bd)
+    grid = (e, c_pad // bc, f // bf, d // bd)
 
     def x_map(ei, ci, fi, di):
         return (ei, ci, di)
@@ -167,10 +189,10 @@ def split_grouped_gemm(
             pl.BlockSpec((1, bd, bf), wr_map),
         ],
         out_specs=pl.BlockSpec((1, bc, bf), o_map),
-        out_shape=jax.ShapeDtypeStruct((e, c, f), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, c_pad, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
         interpret=resolve_interpret(interpret),
-    )(x, w_local, w_remote)
+    )(x, w_local, w_remote)[:, :c]
 
 
 # ==========================================================================
@@ -239,19 +261,44 @@ def _swiglu_kernel(
         o_ref[0] = acc_y[...].astype(o_ref.dtype)
 
 
-# fp32 scratch budget for the fused SwiGLU accumulators. When the
-# unblocked (bc, D) down accumulator (+ gate/up tiles) would exceed it,
-# the down projection's output dim is blocked automatically.
-_ACC_BUDGET_BYTES = 8 * 1024 * 1024
+# VMEM budget of one fused SwiGLU grid step: the fp32 accumulators, the
+# f32 results of its dots, and every streamed tile, double-buffered by
+# the Pallas pipeline. 10 MiB leaves Mosaic headroom under the 16 MiB
+# default scoped-VMEM limit of TPU v4/v5e/v5p. When the unblocked
+# (bc, D) down projection does not fit, the gate/up F tile shrinks to
+# 128 lanes first (no recompute), then the down projection's output dim
+# is blocked.
+_VMEM_BUDGET_BYTES = 10 * 1024 * 1024
 
 
-def _auto_block_o(d: int, bc: int, bf: int) -> int:
-    """Largest output block keeping the fp32 scratch (gate + up + y) and
-    the streamed down tile inside ``_ACC_BUDGET_BYTES``."""
-    fixed = 2 * bc * bf * 4                 # gate + up accumulators
-    avail = max(_ACC_BUDGET_BYTES - fixed, 4 * (bc + bf) * 128)
-    limit = max(avail // (4 * (bc + bf)), 128)  # y acc + down tile per col
+def _block_o_fit(d: int, bc: int, bf: int, bd: int,
+                 x_bytes: int, w_bytes: int) -> int:
+    """Largest output block whose step fits ``_VMEM_BUDGET_BYTES``."""
+    fixed = (
+        2 * bc * bf * 4                     # gate + up fp32 accumulators
+        + 3 * bc * bf * 4                   # gate/up dot results, silu*up
+        + 2 * bc * bd * x_bytes             # x tile
+        + 2 * 4 * bd * bf * w_bytes         # gate + up tiles, both banks
+    )
+    per_col = (
+        2 * bc * 4                          # y accumulator + down dot
+        + 2 * 2 * bf * w_bytes              # down tiles, both banks
+        + 2 * bc * x_bytes                  # output tile
+    )
+    limit = max((_VMEM_BUDGET_BYTES - fixed) // per_col, 128)
     return _pick_block(d, int(limit))
+
+
+def _auto_blocks(d: int, f: int, bc: int, bf: int, bd: int,
+                 x_bytes: int, w_bytes: int) -> tuple[int, int]:
+    """(bf, bo) for the fused SwiGLU kernels: the caller's F tile and the
+    full output dim when they fit, else a 128-lane F tile, else the
+    largest output block that fits (gate/up then recompute per block)."""
+    bo = _block_o_fit(d, bc, bf, bd, x_bytes, w_bytes)
+    if bo < d and bf > 128 and f % 128 == 0:
+        bf = 128
+        bo = _block_o_fit(d, bc, bf, bd, x_bytes, w_bytes)
+    return bf, bo
 
 
 @functools.partial(
@@ -281,8 +328,9 @@ def split_grouped_swiglu(
     beyond the VMEM accumulator budget still lowers: with n_o = D/block_o
     output blocks the gate/up stages are recomputed once per block (the
     standard recompute-vs-residency trade), and ``block_o=None``
-    auto-selects — the full D (today's single-pass schedule) whenever it
-    fits ``_ACC_BUDGET_BYTES``, the largest fitting divisor otherwise.
+    auto-selects (:func:`_auto_blocks`) — the full D (the single-pass
+    schedule) whenever the step's tiles fit ``_VMEM_BUDGET_BYTES``, the
+    largest fitting divisor otherwise.
     """
     e, c, d = x.shape
     e_l, _, f = wg_local.shape
@@ -294,12 +342,19 @@ def split_grouped_swiglu(
     n_wl = wg_local.shape[0]
     n_wr = wg_remote.shape[0]
 
-    bc = _pick_block(c, block_c)
+    c_pad, bc = _row_block(c, block_c)
+    x = _pad_rows(x, 1, c_pad)
     bf = _pick_block(f, block_f)
     bd = _pick_block(d, block_d)
-    bo = _auto_block_o(d, bc, bf) if block_o is None else _pick_block(d, block_o)
+    if block_o is None:
+        bf, bo = _auto_blocks(
+            d, f, bc, bf, bd, x.dtype.itemsize,
+            max(wg_local.dtype.itemsize, wg_remote.dtype.itemsize),
+        )
+    else:
+        bo = _pick_block(d, block_o)
 
-    grid = (e, c // bc, d // bo, f // bf, d // bd)
+    grid = (e, c_pad // bc, d // bo, f // bf, d // bd)
 
     def x_map(ei, ci, oi, fi, di):
         return (ei, ci, di)
@@ -332,14 +387,14 @@ def split_grouped_swiglu(
             pl.BlockSpec((1, bf, bo), down_r_map),
         ],
         out_specs=pl.BlockSpec((1, bc, bo), o_map),
-        out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, c_pad, d), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bo), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(x, wg_local, wu_local, wd_local, wg_remote, wu_remote, wd_remote)
+    )(x, wg_local, wu_local, wd_local, wg_remote, wu_remote, wd_remote)[:, :c]
 
 
 # ==========================================================================
@@ -360,9 +415,8 @@ def _swiglu_demand_kernel(
     # fetched rows past the requester's valid count are clamped junk: the
     # mask keeps them off the MXU entirely, so the budget padding costs
     # no FLOPs and their output blocks flush the zeroed accumulator.
-    is_fetched = jnp.logical_and(
-        jnp.logical_not(is_local), v_ref[0, 0] != 0
-    )
+    row = jnp.clip(e - n_local, 0, v_ref.shape[0] - 1)
+    is_fetched = jnp.logical_and(jnp.logical_not(is_local), v_ref[row] != 0)
 
     @pl.when(jnp.logical_and(fi == 0, di == 0))
     def _init_y():
@@ -438,8 +492,8 @@ def split_grouped_swiglu_demand(
     Identical streaming structure to :func:`split_grouped_swiglu` —
     same grid, same accumulators, same auto block selection, so a
     routed expert's (C, D) block computes bit-identically to the
-    all-fetch split path — plus the per-row validity scalar (SMEM)
-    predicating every MXU stage of the fetched bank. No buffer wider
+    all-fetch split path — plus the per-row validity mask (whole in
+    SMEM) predicating every MXU stage of the fetched bank. No buffer wider
     than ``E_l + E_f`` experts exists anywhere."""
     e, c, d = x.shape
     e_l, _, f = wg_local.shape
@@ -451,22 +505,26 @@ def split_grouped_swiglu_demand(
     wd_local, wd_fetched = _dummy_banks(e_l, e_f, wd_local, wd_fetched, (1, f, d))
     n_wl = wg_local.shape[0]
     n_wf = wg_fetched.shape[0]
-    v = valid.astype(jnp.int32).reshape(-1, 1)
+    v = valid.astype(jnp.int32)
     if e_f == 0:
-        v = jnp.zeros((1, 1), jnp.int32)
+        v = jnp.zeros((1,), jnp.int32)
 
-    bc = _pick_block(c, block_c)
+    c_pad, bc = _row_block(c, block_c)
+    x = _pad_rows(x, 1, c_pad)
     bf = _pick_block(f, block_f)
     bd = _pick_block(d, block_d)
-    bo = _auto_block_o(d, bc, bf) if block_o is None else _pick_block(d, block_o)
+    if block_o is None:
+        bf, bo = _auto_blocks(
+            d, f, bc, bf, bd, x.dtype.itemsize,
+            max(wg_local.dtype.itemsize, wg_fetched.dtype.itemsize),
+        )
+    else:
+        bo = _pick_block(d, block_o)
 
-    grid = (e, c // bc, d // bo, f // bf, d // bd)
+    grid = (e, c_pad // bc, d // bo, f // bf, d // bd)
 
     def x_map(ei, ci, oi, fi, di):
         return (ei, ci, di)
-
-    def v_map(ei, ci, oi, fi, di):
-        return (jnp.clip(ei - e_l, 0, n_wf - 1), 0)
 
     def up_l_map(ei, ci, oi, fi, di):
         return (jnp.clip(ei, 0, n_wl - 1), di, fi)
@@ -488,7 +546,9 @@ def split_grouped_swiglu_demand(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, bd), x_map),
-            pl.BlockSpec((1, 1), v_map, memory_space=pltpu.SMEM),
+            # the whole (E_f,) mask sits in SMEM for the kernel's life:
+            # a per-step (1, 1) block would break Mosaic's tiling rule
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, bd, bf), up_l_map),
             pl.BlockSpec((1, bd, bf), up_l_map),
             pl.BlockSpec((1, bf, bo), down_l_map),
@@ -497,11 +557,12 @@ def split_grouped_swiglu_demand(
             pl.BlockSpec((1, bf, bo), down_f_map),
         ],
         out_specs=pl.BlockSpec((1, bc, bo), o_map),
-        out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((e, c_pad, d), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bf), jnp.float32),
             pltpu.VMEM((bc, bo), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
-    )(x, v, wg_local, wu_local, wd_local, wg_fetched, wu_fetched, wd_fetched)
+    )(x, v, wg_local, wu_local, wd_local, wg_fetched, wu_fetched,
+      wd_fetched)[:, :c]

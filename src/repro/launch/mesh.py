@@ -6,32 +6,39 @@ first device query.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
 from repro.compat import make_mesh
 
 
-def _mesh(shape, axes):
-    """A mesh of ``prod(shape)`` devices. When the shape covers every
-    visible device this is :func:`repro.compat.make_mesh`; a SMALLER
-    shape builds a SUB-mesh over the first ``prod(shape)`` devices —
-    what a rank-death standby replica runs on (the shrunk ``G'-1``
-    subgroup excludes the quarantined device)."""
-    import math
-
+def _mesh(shape, axes, devices=None):
+    """A mesh of ``prod(shape)`` devices. ``devices`` is the explicit
+    device slice to build it on (a serving replica's own chips); by
+    default the first ``prod(shape)`` visible devices. When the shape
+    covers every visible device this is :func:`repro.compat.make_mesh`
+    over the physical topology; a SMALLER shape builds a SUB-mesh — what
+    a rank-death standby replica runs on (the shrunk ``G'-1`` subgroup
+    excludes the quarantined device)."""
     n = math.prod(shape)
-    devices = jax.devices()
-    if n == len(devices):
+    if devices is not None:
+        devices = list(devices)
+        if len(devices) != n:
+            raise ValueError(
+                f"mesh shape {shape} needs {n} devices; given "
+                f"{len(devices)}"
+            )
+        return make_mesh(shape, axes, devices=devices)
+    visible = jax.devices()
+    if n == len(visible):
         return make_mesh(shape, axes)
-    if n > len(devices):
+    if n > len(visible):
         raise ValueError(
             f"mesh shape {shape} needs {n} devices; only "
-            f"{len(devices)} visible"
+            f"{len(visible)} visible"
         )
-    import numpy as np
-    from jax.sharding import Mesh
-
-    return Mesh(np.asarray(devices[:n]).reshape(shape), axes)
+    return make_mesh(shape, axes, devices=visible[:n])
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -39,9 +39,12 @@ import json
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_arch, reduced_variant
 from repro.core.strategy import PolicyTable
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.transformer import build_model
 from repro.runtime.engine import (
     ContextServer,
@@ -143,16 +146,28 @@ def build_engine(
     health: "HealthMonitor | None" = None,
     variant_cache_size: int = 16,
     switch_interval: int = 8,
+    devices=None,
 ):
+    """A :class:`DisaggregatedEngine` and its model. ``devices`` is the
+    explicit device slice the engine's mesh is built on (a serving
+    replica's own chips); by default the first ``prod(mesh_shape)``
+    visible devices."""
     from repro.launch.mesh import _mesh
-    mesh = _mesh(mesh_shape, ("data", "model"))
+    mesh = _mesh(mesh_shape, ("data", "model"), devices=devices)
     sizes = {"data": mesh_shape[0], "model": mesh_shape[1]}
     # seq-sharded KV capture / decode caches split the ring over up to
     # all mesh ranks: round the cache up so every shard degree divides
     n_ranks = max(1, mesh_shape[0] * mesh_shape[1])
     cache_len = -(-cache_len // n_ranks) * n_ranks
     model = build_model(cfg, sizes, dtype=dtype)
-    params = model.init_params(jax.random.key(seed))
+    # initialize on the mesh's own first device, then commit each leaf
+    # to its sharding, so a replica's weights sit on its own devices
+    with jax.default_device(mesh.devices.flat[0]):
+        params = model.init_params(jax.random.key(seed))
+    params = jax.device_put(params, jax.tree.map(
+        lambda s: NamedSharding(mesh, s), model.param_pspecs(),
+        is_leaf=lambda s: isinstance(s, P),
+    ))
     ctx = ContextServer(
         model, mesh, sizes, mode=ctx_mode, prefill_len=prefill_len,
         prefill_buckets=prefill_buckets,
@@ -208,10 +223,22 @@ def run_serving(args, cfg, policy):
         max_queue=args.max_queue,
     )
     gated = args.slo_tps_user or args.slo_ttft or args.max_queue
+    # every replica runs on mesh (1, 1) of its own devices: replica i
+    # holds devices [i*n, (i+1)*n)
+    mesh_shape = (1, 1)
+    n = mesh_shape[0] * mesh_shape[1]
+    devices = jax.devices()
+    if args.replicas * n > len(devices):
+        raise ValueError(
+            f"--replicas {args.replicas} needs {args.replicas * n} "
+            f"devices; only {len(devices)} visible"
+        )
     schedulers = []
     for i in range(args.replicas):
         engine, _ = build_engine(
             cfg,
+            mesh_shape=mesh_shape,
+            devices=devices[i * n:(i + 1) * n],
             prefill_len=max(buckets),
             prefill_buckets=buckets,
             cache_len=max(buckets) + args.output_len,
@@ -394,6 +421,7 @@ def main(argv=None):
         policy = resolve_cli_policy(args)
     except ValueError as e:
         ap.error(str(e))
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if not args.full:
         cfg = reduced_variant(cfg)

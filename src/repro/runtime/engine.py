@@ -20,6 +20,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import execution, roofline
 from repro.core.strategy import (
@@ -79,7 +81,8 @@ def variant_key(table: PolicyTable, shape: InputShape,
 
 class CountingStep:
     """A jitted step function with a call counter and a compile-cache
-    probe, preserving the jit surface (``.lower``) the AOT tests use.
+    probe, preserving the jit surface (``.trace``, ``.lower``) the AOT
+    tests and the chip smoke run use.
 
     ``cache_size()`` reads the underlying jit executable cache — after a
     variant is warmed, the serving path asserts this number stays flat
@@ -92,6 +95,10 @@ class CountingStep:
     def __call__(self, *args, **kwargs):
         self.calls += 1
         return self._fn(*args, **kwargs)
+
+    @property
+    def trace(self):
+        return self._fn.trace
 
     @property
     def lower(self):
@@ -614,9 +621,9 @@ class ContextServer:
             validate_fetch=validate_fetch, capture_len=cache_len,
             max_entries=max(16, len(self.prefill_lens)),
         )
-        self.xp, self.step, self.gather_bytes = self._bucket(prefill_len)
+        self.xp, self.step, self.gather_bytes = self.bucket(prefill_len)
 
-    def _bucket(self, length: int):
+    def bucket(self, length: int):
         """The (plan, step, wire-bytes) variant of one prefill-length
         bucket (built on first use; warm after :meth:`warmup`)."""
         return self.variants.get(
@@ -629,10 +636,19 @@ class ContextServer:
         the serving path (the first real request of any bucketed length
         then hits a warm jit cache)."""
         for length in self.prefill_lens:
-            _, step, _ = self._bucket(length)
+            _, step, _ = self.bucket(length)
             if step.calls == 0:
                 self.prefill(params, np.zeros(length, np.int32))
                 step.calls = 0
+
+    def prefill_logits(self, params, tokens: np.ndarray) -> jax.Array:
+        """Last-position logits ``(1, vocab_pad)`` of one prompt of ANY
+        length — for checks against a reference, off the serving path:
+        a length outside the bucket set builds and compiles its own
+        variant on first use."""
+        _, step, _ = self.bucket(len(tokens))
+        row = jnp.asarray(np.asarray(tokens)[None, :], jnp.int32)
+        return step(params, {"tokens": row})["last_logits"]
 
     def prefill(self, params, tokens: np.ndarray):
         """tokens: (prompt_len,) -> (first_token, state). The prompt
@@ -641,7 +657,7 @@ class ContextServer:
         are exercised by the cluster simulator."""
         length = len(tokens)
         assert length in self.prefill_lens, (length, self.prefill_lens)
-        self.xp, self.step, self.gather_bytes = self._bucket(length)
+        self.xp, self.step, self.gather_bytes = self.bucket(length)
         row = jnp.asarray(tokens[None, :], jnp.int32)
         out = self.step(params, {"tokens": row})
         logits = out["last_logits"]
@@ -720,49 +736,31 @@ class GenerationServer:
         )
 
     def _committed(self, state, xp):
-        """The decode state committed to the step's OUTPUT shardings.
-        The jit executable cache keys on input shardings, so a
-        freshly-built host-backed state would compile a throwaway
-        executable distinct from the steady-state one whose inputs are
-        the previous step's (committed) outputs — committing here gives
-        boot, warmup and serving calls ONE signature, which is what lets
-        the warmup pass guarantee zero serving-path recompiles."""
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
-        specs = execution.state_pspecs(self.model, xp)
-        pred = execution.predict_state_pspecs(self.model, xp)
-        if "pred" in state:
-            specs = dict(specs)
-            specs["pred"] = pred
-
-        def canon(s):
-            # the jit's output shardings carry trailing-None-stripped
-            # specs; commit to the same canonical form or the cache
-            # keys won't collide
-            parts = tuple(s)
-            while parts and parts[-1] is None:
-                parts = parts[:-1]
-            return P(*parts)
-
+        """The decode state committed to the step's pinned OUTPUT
+        shardings (:func:`execution.state_shardings`). The jit executable
+        cache keys on input shardings, spelling included, so a state
+        built on the host or updated by an eager op (``admit``) would
+        compile a throwaway executable distinct from the steady-state
+        one whose inputs are the previous step's outputs — committing
+        here gives boot, warmup, admit and serving calls ONE signature,
+        which is what lets the warmup pass guarantee zero serving-path
+        recompiles. A leaf already on its sharding is not copied."""
+        shardings = execution.state_shardings(self.model, xp, self._mesh)
+        if "pred" not in state:
+            shardings.pop("pred", None)
         return jax.tree.map(
             # optional PredictState leaves (the richer-predictor fields)
             # are None in plain predictive mode — in both the state and
-            # its spec tree — and stay None
-            lambda x, s: x if x is None else jax.device_put(
-                x, NamedSharding(self._mesh, canon(s))
-            ),
-            state, specs,
+            # its sharding tree — and stay None
+            lambda x, s: x if x is None else jax.device_put(x, s),
+            state, shardings,
             is_leaf=lambda x: x is None,
         )
 
     def _committed_token(self, tok):
-        """The token row committed to the decode step's next_token
-        output sharding (same signature-stability argument as
+        """The token row committed to the decode step's pinned
+        next_token output sharding (same signature-stability argument as
         :meth:`_committed`)."""
-        from jax.sharding import NamedSharding
-        from jax.sharding import PartitionSpec as P
-
         return jax.device_put(
             tok, NamedSharding(self._mesh, P(self.xp.batch_spec(), None))
         )
@@ -883,15 +881,10 @@ class GenerationServer:
                                   self.cache_len),
                 self.model, xp,
             ), xp)
-            # two chained calls: the first runs the boot-state signature
-            # (freshly-committed inputs — what the server sees right
-            # after a switch re-commits its state), the second runs the
-            # steady-state signature (the previous step's outputs, whose
-            # sharding spellings the jit normalizes differently). Both
-            # land in the dispatch cache, so neither the first
-            # post-switch step nor any later step re-keys.
-            out = step(params, {"token": tok}, state)
-            step(params, {"token": out["next_token"]}, out["state"])
+            # committed inputs and the step's pinned outputs share one
+            # spelling, so this one call warms the boot, post-switch and
+            # steady-state signatures alike
+            step(params, {"token": tok}, state)
             step.calls = 0
             compiled += 1
         return compiled
@@ -934,8 +927,13 @@ class GenerationServer:
         }
         if "pred" in self.state:
             new_state["pred"] = self.state["pred"]
-        self.state = new_state
-        self.cur_token = self.cur_token.at[slot, 0].set(first_token)
+        # the eager slot writes leave their outputs on whatever sharding
+        # the op picks (a seq-sharded KV cache comes back replicated over
+        # "model"): re-commit, or the next decode step compiles anew
+        self.state = self._committed(new_state, self.xp)
+        self.cur_token = self._committed_token(
+            self.cur_token.at[slot, 0].set(first_token)
+        )
         self.slot_req[slot] = req_id
 
     def decode_step(self, params):
